@@ -14,7 +14,12 @@ from __future__ import annotations
 import argparse
 import time
 
-from benchmarks.common import banner, emit, write_bench_json
+from benchmarks.common import (
+    banner,
+    emit,
+    enable_compile_cache,
+    write_bench_json,
+)
 from repro.kvsim import (
     ClusterConfig,
     RedynisPolicy,
@@ -89,6 +94,7 @@ def main(
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--num-requests", type=int, default=50_000)
     ap.add_argument("--backend", choices=("jax", "pallas"), default="jax")

@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 __all__ = [
+    "enable_compile_cache",
     "time_fn",
     "emit",
     "banner",
@@ -37,6 +38,24 @@ WAN5_WORKLOAD_KWARGS = dict(
     region_weights=(0.35, 0.25, 0.20, 0.12, 0.08),
     affinity=0.8,
 )
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+    reads it itself and nothing is set here. Otherwise the cache lives at
+    the fixed ``<repo>/.jax_cache``: the path is part of the cache key, so
+    a path that moved between runs would never hit. Entry points call this;
+    importing the library never does."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache",
+    )
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def dedupe_policies(candidates, num_nodes: int) -> list:

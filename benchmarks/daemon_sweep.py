@@ -14,7 +14,13 @@ import time
 import jax
 import jax.numpy as jnp
 
-from benchmarks.common import banner, emit, time_fn, write_bench_json
+from benchmarks.common import (
+    banner,
+    emit,
+    enable_compile_cache,
+    time_fn,
+    write_bench_json,
+)
 from repro.core.metadata import create_store
 from repro.core.placement import masked_step, sweep
 from repro.core.policy import (
@@ -161,6 +167,7 @@ def main(
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument(
         "--backend", choices=("jax", "pallas", "both"), default="both",

@@ -56,7 +56,12 @@ import time
 
 import numpy as np
 
-from benchmarks.common import banner, emit, write_bench_json
+from benchmarks.common import (
+    banner,
+    emit,
+    enable_compile_cache,
+    write_bench_json,
+)
 from repro.kvsim import (
     RoutingConfig,
     StaticPolicy,
@@ -281,6 +286,7 @@ def main(
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--num-requests", type=int, default=100_000)
     ap.add_argument("--num-keys", type=int, default=1_000)

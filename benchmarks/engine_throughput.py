@@ -33,14 +33,17 @@ generation, bit-exact with the materialised path), every row records
 ``peak_live_bytes`` — the analytic peak live-buffer footprint (trace
 window + per-key state planes, the O(requests) → O(chunk + keys/shard)
 memory win as a tracked column — see ``_peak_live_bytes``), and
-``--trendline`` adds the multi-device scaling trendline: one subprocess
-per device count (``XLA_FLAGS=--xla_force_host_platform_device_count=S``
-must be set before jax initialises, hence the fresh interpreter per
-point) runs the key-sharded streamed engine — routing tier off AND on in
-that SAME subprocess — and reports requests/sec, ``scaling_vs_1shard``,
-and ``routing_on_off_ratio`` (the directory tier's wall-clock cost
-multiple, a machine-independent ratio since PR 8). The spec-scale run targets 100M+ requests over
-10⁷ keys (``--trendline-requests 100000000 --trendline-keys 10000000``);
+``--trendline`` adds the multi-device scaling trendline: for each shard
+count S, this process runs the key-sharded streamed engine over
+``jax.devices()[:S]`` — routing tier off AND on — and reports
+requests/sec, ``scaling_vs_1shard``, and ``routing_on_off_ratio`` (the
+directory tier's wall-clock cost multiple, a machine-independent ratio
+since PR 8). One process holds every device, so on a TPU host no child
+ever competes for a chip; the CPU rehearsal sets
+``XLA_FLAGS=--xla_force_host_platform_device_count=S`` (the largest
+count) once, before the script starts. The spec-scale run targets 100M+
+requests over 10⁷ keys
+(``--trendline-requests 100000000 --trendline-keys 10000000``);
 the checked-in baseline records a CI-tractable configuration of the same
 shape. ``--scale-acceptance`` times one ≥10M-request streamed run on a
 single device (the run the materialised path cannot fit at accelerator
@@ -69,8 +72,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import subprocess
-import sys
 import time
 from functools import partial
 
@@ -82,6 +83,7 @@ from benchmarks.common import (
     WAN5_WORKLOAD_KWARGS,
     banner,
     emit,
+    enable_compile_cache,
     write_bench_json,
 )
 from repro.core.metadata import record_accesses
@@ -467,11 +469,10 @@ def check_regression(rows, baseline_path, threshold=0.20, speedups=None,
 
 
 # ---------------------------------------------------------------------------
-# Multi-device trendline: one subprocess per virtual device count.
+# Multi-device trendline: every shard count measured in this one process.
 # ---------------------------------------------------------------------------
 
 TRENDLINE_DEVICE_COUNTS = (1, 2, 4, 8)
-_TRENDLINE_MARK = "TRENDLINE_ROW,"
 
 
 # The routing-tier configuration the trendline prices: lagged publishes
@@ -485,14 +486,10 @@ def _trendline_routing(num_keys):
     return RoutingConfig(publish_lag_chunks=8)
 
 
-def _trendline_worker(num_shards, num_requests, num_keys, repeats,
-                      daemon_interval, policy_spec):
-    """Runs inside the forced-device-count subprocess: measure the streamed
-    key-sharded run with the routing tier OFF and ON — both in this ONE
-    subprocess (one backend init, one warmed cache per side; spawning a
-    second interpreter per device count would double the dominant
-    fixed cost and put the two sides of the ratio in different processes)
-    — and print the row as a machine-readable line.
+def _trendline_row(num_shards, num_requests, num_keys, repeats,
+                   daemon_interval, policy_spec):
+    """Measure the streamed key-sharded run over ``num_shards`` devices
+    with the routing tier OFF and ON.
 
     ``routing_on_off_ratio`` divides per-side minima (routing-on /
     routing-off wall time, so 1.10 = the directory tier costs 10%): both
@@ -510,7 +507,7 @@ def _trendline_worker(num_shards, num_requests, num_keys, repeats,
         "scan", pol, wl, routed, daemon_interval, None, "jax",
         repeats, trace_mode="streamed", num_shards=num_shards,
     )
-    row = {
+    return {
         "policy": policy_spec, "num_requests": num_requests,
         "num_keys": num_keys, "num_shards": num_shards,
         "daemon_interval": daemon_interval, "trace_mode": "streamed",
@@ -524,59 +521,31 @@ def _trendline_worker(num_shards, num_requests, num_keys, repeats,
             "streamed", num_shards,
         ),
     }
-    print(_TRENDLINE_MARK + json.dumps(row), flush=True)
 
 
 def run_trendline(device_counts, num_requests, num_keys, repeats,
                   daemon_interval, policy_spec):
-    """The multi-device scaling trendline: re-invoke this script once per
-    device count with ``--xla_force_host_platform_device_count`` forced in
-    the child's environment (the flag is read once at backend init, so a
-    fresh interpreter per point is the only correct spelling — same
-    convention as the multi-rank tests).
+    """The multi-device scaling trendline: one row per shard count, each
+    measured over the first ``S`` of this process's devices.
 
     ``scaling_vs_1shard`` divides per-count minima (same robustness
     argument as ``speedup_vs_legacy``: both sides share one box)."""
+    have = len(jax.devices())
+    if max(device_counts) > have:
+        raise SystemExit(
+            f"FAIL,trendline needs {max(device_counts)} devices, have {have} "
+            f"(CPU: set XLA_FLAGS=--xla_force_host_platform_device_count="
+            f"{max(device_counts)} before starting the script)"
+        )
     banner(
         f"trendline: streamed sharded engine, {num_requests:,} requests / "
         f"{num_keys:,} keys, device counts {tuple(device_counts)}"
     )
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env_base = dict(
-        os.environ,
-        PYTHONPATH=os.pathsep.join(
-            [os.path.join(root, "src"), root,
-             os.environ.get("PYTHONPATH", "")]
-        ),
-    )
-    rows = []
-    for s in device_counts:
-        env = dict(
-            env_base,
-            XLA_FLAGS=f"--xla_force_host_platform_device_count={s}",
-        )
-        proc = subprocess.run(
-            [
-                sys.executable, os.path.abspath(__file__),
-                "--trendline-worker", str(s),
-                "--trendline-requests", str(num_requests),
-                "--trendline-keys", str(num_keys),
-                "--trendline-policy", policy_spec,
-                "--repeats", str(repeats),
-                "--daemon-intervals", str(daemon_interval),
-            ],
-            capture_output=True, text=True, env=env,
-        )
-        if proc.returncode != 0:
-            raise SystemExit(
-                f"FAIL,trendline worker (num_shards={s}) exited "
-                f"{proc.returncode}:\n{proc.stdout}{proc.stderr}"
-            )
-        line = next(
-            ln for ln in proc.stdout.splitlines()
-            if ln.startswith(_TRENDLINE_MARK)
-        )
-        rows.append(json.loads(line[len(_TRENDLINE_MARK):]))
+    rows = [
+        _trendline_row(s, num_requests, num_keys, repeats, daemon_interval,
+                       policy_spec)
+        for s in device_counts
+    ]
     base_min = rows[0]["wall_s_min"]
     for row in rows:
         row["scaling_vs_1shard"] = base_min / row["wall_s_min"]
@@ -903,6 +872,7 @@ def main(
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--num-requests", type=int, default=200_000)
     ap.add_argument("--repeats", type=int, default=5)
@@ -934,8 +904,8 @@ if __name__ == "__main__":
                     help="run the 1M-request ISSUE-5 acceptance comparison")
     ap.add_argument(
         "--trendline", action="store_true",
-        help="measure the multi-device scaling trendline (one forced-"
-        "device-count subprocess per point, streamed sharded engine)",
+        help="measure the multi-device scaling trendline (streamed sharded "
+        "engine over the first S devices, for each S of --trendline-devices)",
     )
     ap.add_argument(
         "--trendline-devices", nargs="+", type=int,
@@ -944,10 +914,6 @@ if __name__ == "__main__":
     ap.add_argument("--trendline-requests", type=int, default=2_000_000)
     ap.add_argument("--trendline-keys", type=int, default=200_000)
     ap.add_argument("--trendline-policy", default="redynis")
-    ap.add_argument(
-        "--trendline-worker", type=int, metavar="NUM_SHARDS", default=None,
-        help=argparse.SUPPRESS,  # internal: the per-device-count subprocess
-    )
     ap.add_argument(
         "--scale-acceptance", action="store_true",
         help="time one >=10M-request streamed run on a single device "
@@ -972,13 +938,6 @@ if __name__ == "__main__":
         "phases carry jax.named_scope annotations)",
     )
     args = ap.parse_args()
-    if args.trendline_worker is not None:
-        _trendline_worker(
-            args.trendline_worker, args.trendline_requests,
-            args.trendline_keys, args.repeats, args.daemon_intervals[0],
-            args.trendline_policy,
-        )
-        raise SystemExit(0)
     main(
         num_requests=args.num_requests,
         repeats=args.repeats,

@@ -15,7 +15,13 @@ from __future__ import annotations
 
 import time
 
-from benchmarks.common import banner, emit, json_rows, write_bench_json
+from benchmarks.common import (
+    banner,
+    emit,
+    enable_compile_cache,
+    json_rows,
+    write_bench_json,
+)
 from repro.kvsim import RedynisPolicy, StaticPolicy, run_experiment
 
 # The paper's four scenarios as policies, keyed by the figure's labels.
@@ -106,4 +112,5 @@ def main(
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

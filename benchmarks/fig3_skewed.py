@@ -10,7 +10,13 @@ from __future__ import annotations
 
 import time
 
-from benchmarks.common import banner, emit, json_rows, write_bench_json
+from benchmarks.common import (
+    banner,
+    emit,
+    enable_compile_cache,
+    json_rows,
+    write_bench_json,
+)
 from repro.kvsim import (
     ClusterConfig,
     RedynisPolicy,
@@ -138,4 +144,5 @@ def main(
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

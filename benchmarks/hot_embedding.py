@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import banner, emit, time_fn
+from benchmarks.common import banner, emit, enable_compile_cache, time_fn
 from repro.core.hot_embedding import HotEmbedding, embed_with_cache
 
 
@@ -66,4 +66,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -23,6 +23,7 @@ from benchmarks.common import (
     banner,
     dedupe_policies,
     emit,
+    enable_compile_cache,
     write_bench_json,
 )
 from repro.kvsim import (
@@ -167,6 +168,7 @@ def main(
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--num-requests", type=int, default=30_000)
     ap.add_argument("--read-fraction", type=float, default=0.9)

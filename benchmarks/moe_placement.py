@@ -13,7 +13,7 @@ import dataclasses
 
 import jax
 
-from benchmarks.common import banner, emit
+from benchmarks.common import banner, emit, enable_compile_cache
 from repro.configs import get_config, reduced
 from repro.data.pipeline import DataConfig, Pipeline
 from repro.models import build
@@ -70,4 +70,5 @@ def main(steps: int = 40) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -34,6 +34,8 @@ import inspect
 import sys
 import time
 
+from benchmarks.common import enable_compile_cache
+
 MODULES = [
     "fig2_uniform",
     "fig3_skewed",
@@ -96,4 +98,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.common import banner, emit
+from benchmarks.common import banner, emit, enable_compile_cache
 from repro.serving import SessionRouter
 
 
@@ -61,4 +61,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -27,6 +27,7 @@ from benchmarks.common import (
     banner,
     dedupe_policies,
     emit,
+    enable_compile_cache,
     write_bench_json,
 )
 from repro.kvsim import (
@@ -237,6 +238,7 @@ def main(
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--num-requests", type=int, default=30_000)
     ap.add_argument("--iterations", type=int, default=3)

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax import Array
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 __all__ = [
     "DistSpec",
@@ -134,12 +133,12 @@ def embed_lookup(table: Array, tokens: Array, dist: Optional[DistSpec]) -> Array
         for a in lb:
             n_rows *= dist.mesh.shape[a]
     bspec = lb if tokens.shape[0] % max(n_rows, 1) == 0 else None
-    return shard_map(
+    return jax.shard_map(
         local_lookup,
         mesh=dist.mesh,
         in_specs=(P(axis, None), P(bspec, None)),
         out_specs=P(bspec, None, None),
-        check_rep=False,
+        check_vma=False,
     )(table, tokens)
 
 

@@ -158,8 +158,11 @@ def chunk_replay_kernel(
                 has_local = hit
 
             # --- 2. read path: nearest visible replica over the RTT row.
+            # HIGHEST: a TPU matmul otherwise rounds f32 operands to
+            # bf16, and this gather must return the RTTs exactly.
             row = jax.lax.dot_general(
                 onehot_n, rtt, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32,
             )  # [TR, N] — exact gather (one nonzero term per sum)
             masked = jnp.where(read_replicas, row, jnp.inf)
@@ -200,6 +203,7 @@ def chunk_replay_kernel(
         read_hits = hit & is_read & valid
         busy_ref[...] += jax.lax.dot_general(
             lat, onehot_n, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,  # full-f32 latencies
             preferred_element_type=jnp.float32,
         )  # [1, N]
         w = valid.astype(jnp.float32)

@@ -1,6 +1,6 @@
 """Shared Pallas utilities: TPU detection, compiler params, VMEM scratch.
 
-Kernels in this package target TPU (Mosaic). On this CPU container they are
+Kernels in this package target TPU (Mosaic). Off the TPU they are
 validated with ``interpret=True`` — the kernel body executes in Python with
 identical semantics, so the allclose-vs-oracle tests exercise the real
 tiling/masking logic. ``ops.py`` wrappers pick the mode automatically.
@@ -8,17 +8,10 @@ tiling/masking logic. ``ops.py`` wrappers pick the mode automatically.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-
 from jax.experimental import pallas as pl
-
-try:  # Mosaic-TPU extras (present in this jax build; guarded for portability)
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["pl", "pltpu", "on_tpu", "interpret_default", "compiler_params", "vmem_scratch", "NEG_INF"]
 
@@ -34,22 +27,11 @@ def interpret_default() -> bool:
     return not on_tpu()
 
 
-def compiler_params(dimension_semantics: tuple[str, ...] | None = None):
+def compiler_params(dimension_semantics: tuple[str, ...]):
     """Mosaic compiler params (dimension semantics drive pipelining)."""
-    if pltpu is None or dimension_semantics is None:
-        return None
-    for cls_name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, cls_name, None)
-        if cls is not None:
-            try:
-                return cls(dimension_semantics=dimension_semantics)
-            except TypeError:  # pragma: no cover - signature drift
-                continue
-    return None  # pragma: no cover
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
 
 def vmem_scratch(shape: tuple[int, ...], dtype=jnp.float32):
-    """A VMEM scratch allocation (falls back to ANY in interpret mode)."""
-    if pltpu is not None:
-        return pltpu.VMEM(shape, dtype)
-    return pl.BlockSpec(memory_space=None)  # pragma: no cover
+    """A VMEM scratch allocation."""
+    return pltpu.VMEM(shape, dtype)

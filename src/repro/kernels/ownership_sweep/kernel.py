@@ -52,27 +52,33 @@ def ownership_sweep_kernel(
     n: int,
     tk: int,
 ):
+    # Mosaic lowers i1 planes poorly (no i8 -> i1 truncation, no lane
+    # broadcast of an i1 column), so every [TK, 1] column is broadcast to
+    # [TK, N] as a number first and compared there.
+    plane = lambda col: jnp.broadcast_to(col, (tk, n))
     counts = counts_ref[...]
-    hosts = hosts_ref[...] != 0
-    live = live_ref[...] != 0  # [TK, 1]
+    hosts = hosts_ref[...].astype(jnp.int32) != 0
+    live = live_ref[...].astype(jnp.int32)  # [TK, 1] 0/1
     h = h_ref[0, 0]
 
-    total = jnp.sum(counts, axis=-1, keepdims=True)  # [TK, 1]
-    f = jnp.where(total > 0, counts / jnp.maximum(total, 1.0), 0.0)
+    total = plane(jnp.sum(counts, axis=-1, keepdims=True))
+    touched = total > 0
+    f = jnp.where(touched, counts / jnp.maximum(total, 1.0), 0.0)
     elig = f >= h
     # Starvation guard: traffic but nobody qualifies -> hottest node keeps it.
     iota_n = jax.lax.broadcasted_iota(jnp.int32, (tk, n), 1)
-    am = jnp.argmax(counts, axis=-1)[:, None]
-    none_q = (total > 0) & ~jnp.any(elig, axis=-1, keepdims=True)
-    elig = jnp.where(none_q, iota_n == am, elig)
+    am = plane(jnp.argmax(counts, axis=-1)[:, None].astype(jnp.int32))
+    n_elig = plane(jnp.sum(elig.astype(jnp.int32), axis=-1, keepdims=True))
+    none_q = touched & (n_elig == 0)
+    elig = (none_q & (iota_n == am)) | (~none_q & elig)
 
-    owners = jnp.where(total > 0, elig, hosts)  # silence = no churn
+    owners = (touched & elig) | (~touched & hosts)  # silence = no churn
     if expiry > 0:
         now = now_ref[0, 0]
-        expired = live & ((now - last_ref[...]) > expiry)
+        expired = live * ((now - last_ref[...]) > expiry).astype(jnp.int32)
     else:
         expired = jnp.zeros_like(live)
-    owners = owners & live & ~expired
+    owners = owners & (plane(live) != 0) & (plane(expired) == 0)
 
     owners_ref[...] = owners.astype(jnp.int8)
     add_ref[...] = (owners & ~hosts).astype(jnp.int8)

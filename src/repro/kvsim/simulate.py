@@ -252,6 +252,16 @@ class SimResult(NamedTuple):
     repair_moves: float = 0.0  # re-replications of copies lost to failures
 
 
+def _busy_fold(lat: Array, nodes: Array, n: int) -> Array:
+    """Per-node latency totals ``[N]`` as a masked reduction over the
+    requests. A scatter-add (or an f32 matmul on CPU) accumulates each
+    node's total in request order, so once the total is large every later
+    latency loses its low bits — 1% of a node's busy time at
+    10**6-request chunks; XLA's reductions accumulate pairwise."""
+    own = nodes[:, None] == jnp.arange(n)[None, :]
+    return jnp.sum(jnp.where(own, lat[:, None], 0.0), axis=0)
+
+
 def _initial_hosts(
     natural_node: Array, num_keys: int, num_nodes: int, placement: str
 ) -> Array:
@@ -704,24 +714,14 @@ def _simulate(
             read_flags = pr & pv
         else:
             read_flags = pr
-        # Per-node busy fold as a [1, R] ∙ [R, N] one-hot matmul — an
-        # order of magnitude faster than a length-R scatter on CPU, and
-        # exact for the integer-ms latency sums the goldens pin.
-        onehot_n = (pn[:, None] == jnp.arange(n)[None, :]).astype(jnp.float32)
-        busy = jax.lax.dot_general(
-            lat[None, :], onehot_n, (((1,), (0,)), ((), ())),
-            # Full-f32 accumulation everywhere: TPU/GPU matmuls otherwise
-            # truncate operands (bf16/TF32) and break the documented
-            # exactness of static-policy aggregates vs the scan engine.
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32,
-        )[0]
+        busy = _busy_fold(lat, pn, n)
         lat_sum = jnp.sum(lat)
-        hits = jnp.sum(read_hits.astype(jnp.float32))
-        reads = jnp.sum(read_flags.astype(jnp.float32))
+        hits = jnp.sum(read_hits.astype(jnp.int32))
+        reads = jnp.sum(read_flags.astype(jnp.int32))
         leaves = (
             r / (jnp.max(busy) / 1000.0),
-            hits / jnp.maximum(reads, 1.0),
+            hits.astype(jnp.float32)
+            / jnp.maximum(reads.astype(jnp.float32), 1.0),
             lat_sum / r,
             busy,
             zero,  # repl
@@ -877,17 +877,21 @@ def _simulate(
             zero,
             zero,
         )
+    # Counters ride the carry as int32: an f32 running total stops being
+    # exact past 2**24, which a deployment-size trace passes (and a
+    # sharded run would then round differently from an unsharded one).
+    izero = jnp.int32(0)
     init = (
         store,
         pstate,
         jnp.zeros((n,), jnp.float32),  # busy
         zero,  # lat_sum
-        zero,  # hits
-        zero,  # reads
-        zero,  # repl
-        zero,  # drop
-        zero,  # evic (expiry)
-        zero,  # cap_evic
+        izero,  # hits
+        izero,  # reads
+        izero,  # repl
+        izero,  # drop
+        izero,  # evic (expiry)
+        izero,  # cap_evic
         occ0,  # peak (seeded by the initial map)
         rcarry0,
         fcarry0,
@@ -1056,7 +1060,7 @@ def _simulate(
             busy = busy + d_busy
         else:
             # Pure-jnp path, op-for-op the pre-fusion engine (bit-exact
-            # with the seed goldens, including the carry-scatter busy).
+            # with the seed goldens).
             with jax.named_scope("chunk_replay"):
                 lat, read_hits = _chunk_latency(
                     hosts_eff, ck, cn, cr, rtt, cluster, policy.read_mode
@@ -1071,11 +1075,11 @@ def _simulate(
             chunk_hits = jnp.sum((read_hits & served).astype(jnp.float32))
             chunk_reads = jnp.sum((cr & served).astype(jnp.float32))
             chunk_count = jnp.sum(served.astype(jnp.float32))
-            busy = busy.at[cn].add(lat)
+            busy = busy + _busy_fold(lat, cn, n)
             hist = None
         lat_sum = lat_sum + chunk_lat
-        hits = hits + chunk_hits
-        reads = reads + chunk_reads
+        hits = hits + chunk_hits.astype(jnp.int32)
+        reads = reads + chunk_reads.astype(jnp.int32)
         zero = jnp.float32(0.0)
         if fault is not None:
             with jax.named_scope("fault_counters"):
@@ -1159,10 +1163,10 @@ def _simulate(
                     policy, pstate, store, c, (c % policy.period) == 0,
                     step_ctx,
                 )
-            repl = repl + stats.adds
-            drop = drop + stats.drops
-            evic = evic + stats.expiry_evictions
-            cap_evic = cap_evic + stats.capacity_evictions
+            repl = repl + stats.adds.astype(jnp.int32)
+            drop = drop + stats.drops.astype(jnp.int32)
+            evic = evic + stats.expiry_evictions.astype(jnp.int32)
+            cap_evic = cap_evic + stats.capacity_evictions.astype(jnp.int32)
             chunk_moves = (
                 stats.adds, stats.drops, stats.expiry_evictions,
                 stats.capacity_evictions,
@@ -1334,8 +1338,11 @@ def _simulate(
         # Served-request mean: unavailable requests produced no latency, so
         # they leave the numerator AND the denominator (throughput keeps
         # dividing the full attempted count — the cluster's offered load).
+        # XLA rewrites the faults-off division by the constant r as a
+        # multiply by its reciprocal; price this one the same way so an
+        # all-up schedule stays bit-exact with faults off.
         served_r = r - fault_totals[0] - fault_totals[1]
-        mean_lat = lat_sum / jnp.maximum(served_r, 1.0)
+        mean_lat = lat_sum * (1.0 / jnp.maximum(served_r, 1.0))
         if not routing_totals:
             # SimResult is constructed positionally and the routing
             # counters are a strict prefix of the fault counters — fill
@@ -1345,7 +1352,8 @@ def _simulate(
         mean_lat = lat_sum / r
     return (
         r / (makespan_ms / 1000.0),
-        hits / jnp.maximum(reads, 1.0),
+        hits.astype(jnp.float32)
+        / jnp.maximum(reads.astype(jnp.float32), 1.0),
         mean_lat,
         busy,
         repl,
@@ -1425,10 +1433,9 @@ def _sharded_simulate_jit(num_shards: int):
     any shard's requests may reference any key; what shards is the O(K·N)
     per-key STATE built inside ``_simulate`` from each shard's
     ``dynamic_slice``. Outputs are psum-assembled global aggregates, so
-    ``out_specs=P()`` (replicated) as well. ``check_rep=False`` because the
+    ``out_specs=P()`` (replicated) as well. ``check_vma=False`` because the
     body mixes shard-local intermediates with psum'd results inside a scan,
     which the replication checker cannot prove."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec
 
     devices = jax.devices()
@@ -1444,13 +1451,13 @@ def _sharded_simulate_jit(num_shards: int):
 
     def wrapped(keys, nodes, is_read, natural, object_bytes, params, seed,
                 **statics):
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda a, b, c, d, e, f, g: _simulate(a, b, c, d, e, f, g,
                                                   **statics),
             mesh=mesh,
             in_specs=(replicated,) * 7,
             out_specs=replicated,
-            check_rep=False,
+            check_vma=False,
         )
         return fn(keys, nodes, is_read, natural, object_bytes, params, seed)
 
@@ -1795,8 +1802,9 @@ def _reference_engine(
             )
             if fault is not None:
                 comps = jnp.where(served[None, :], comps, 0.0)
-        busy = jnp.zeros((n,), jnp.float32).at[nodes].add(lat)
-        total_lat += np.asarray(busy, dtype=np.float64)
+        total_lat += np.bincount(
+            np.asarray(nodes), np.asarray(lat, np.float64), minlength=n
+        )
         chunk_lat = float(jnp.sum(lat))
         chunk_hits = float(jnp.sum(read_hits & served))
         chunk_reads = float(jnp.sum(is_read & served))

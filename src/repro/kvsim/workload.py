@@ -42,17 +42,17 @@ stream positionally instead of temporally:
     on demand — O(chunk) — and is **bit-identical to slicing the
     materialised ``generate_trace`` output** at those positions.
 
-The equivalence works because jax's classic (non-partitionable) threefry
-scheme is counter-based: ``random_bits(key, 32, (n,))`` encrypts the
-counters ``0..n-1`` laid out as two half-length lanes (odd ``n`` pads one
-zero counter). ``_sliced_bits`` reconstructs, for an arbitrary *position*
-vector, exactly the (counter, lane) pair the full-length call would have
-used and binds the threefry primitive on those counters directly — so any
-slice of the stream costs O(slice), not O(n). ``_sliced_randint`` /
-``_sliced_bernoulli`` then replicate ``jax.random``'s bit-to-value
-transforms op-for-op on top. Positions ``>= num_requests`` produce
-well-typed garbage (in-range keys/nodes) that callers must mask — the
-simulation engine's padded-row ``valid`` mask already does.
+The equivalence works because jax's partitionable threefry scheme (the
+default since jax 0.5) is counter-based and positional: element ``p`` of
+``random_bits(key, 32, (n,))`` is the XOR of the two output lanes of
+threefry2x32 on the counter pair ``(0, p)``, whatever ``n`` is.
+``_sliced_bits`` binds the threefry primitive on exactly those counters for
+an arbitrary *position* vector, so any slice of the stream costs O(slice),
+not O(n). ``_sliced_randint`` / ``_sliced_bernoulli`` then replicate
+``jax.random``'s bit-to-value transforms op-for-op on top. Positions
+``>= num_requests`` produce well-typed garbage (in-range keys/nodes) that
+callers must mask — the simulation engine's padded-row ``valid`` mask
+already does.
 """
 
 from __future__ import annotations
@@ -63,7 +63,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import Array
-from jax._src.prng import threefry2x32_p
+from jax.extend.random import threefry2x32_p
 
 __all__ = [
     "WorkloadConfig",
@@ -216,51 +216,38 @@ def generate_trace(cfg: WorkloadConfig, seed: int | Array = 0) -> Trace:
 # ---------------------------------------------------------------------------
 
 
-def _sliced_bits(key: Array, pos: Array, total: int) -> Array:
-    """``jax.random.bits(key, (total,), uint32)[pos]`` in O(|pos|).
-
-    jax's classic threefry layout for a length-``total`` draw: the counters
-    ``iota(total)`` (odd sizes pad one zero) are split into two half-length
-    lanes of ``h = (total+1)//2``, and block ``j`` encrypts the counter pair
-    ``(j, j+h)``. Output position ``p < h`` is lane 0 of block ``p``;
-    ``p >= h`` is lane 1 of block ``p - h``. We bind the threefry primitive
-    on exactly those counters, so any position subset reproduces the full
-    draw's bits without materialising it. Positions ``>= total`` fall into
-    counter space the full draw never used — callers mask those rows.
-    """
-    h = (total + 1) // 2
+def _sliced_bits(key: Array, pos: Array) -> Array:
+    """``jax.random.bits(key, (n,), uint32)[pos]`` for any ``n > pos``, in
+    O(|pos|): the partitionable layout encrypts the counter pair ``(0, p)``
+    for position ``p`` and XORs the two output lanes. Positions past the
+    trace's end are counters the full draw never used — callers mask those
+    rows."""
     p = pos.astype(jnp.uint32)
-    is_lo = pos < h
-    j = jnp.where(is_lo, pos, pos - h).astype(jnp.uint32)
-    # Lane-0 blocks pair with counter j+h — except the final odd block,
-    # whose partner is the zero pad.
-    c1 = jnp.where(is_lo, jnp.where(pos + h < total, p + h, 0), p)
     k0 = jnp.broadcast_to(key[0], pos.shape).astype(jnp.uint32)
     k1 = jnp.broadcast_to(key[1], pos.shape).astype(jnp.uint32)
-    out_lo, out_hi = threefry2x32_p.bind(k0, k1, j, c1)
-    return jnp.where(is_lo, out_lo, out_hi)
+    out0, out1 = threefry2x32_p.bind(k0, k1, jnp.zeros_like(p), p)
+    return out0 ^ out1
 
 
-def _sliced_randint(
-    key: Array, pos: Array, total: int, minval: int, maxval: int
-) -> Array:
-    """``jax.random.randint(key, (total,), minval, maxval)[pos]`` — the
+def _sliced_randint(key: Array, pos: Array, minval: int, maxval: int) -> Array:
+    """``jax.random.randint(key, (n,), minval, maxval)[pos]`` — the
     double-draw modular-reduction transform of ``jax._src.random._randint``
     replicated op-for-op on sliced bits (int32, static python bounds)."""
     k1, k2 = jax.random.split(key)
-    higher = _sliced_bits(k1, pos, total)
-    lower = _sliced_bits(k2, pos, total)
+    higher = _sliced_bits(k1, pos)
+    lower = _sliced_bits(k2, pos)
     span_i = maxval - minval if maxval > minval else 1
     span = np.uint32(span_i)
-    multiplier = np.uint32(((2**16 % span_i) ** 2) % span_i)
+    # jax squares 2**16 % span in uint32, which wraps once span > 2**16.
+    multiplier = np.uint32(((2**16 % span_i) ** 2 % 2**32) % span_i)
     offset = ((higher % span) * multiplier + (lower % span)) % span
     return (minval + offset.astype(jnp.int32)).astype(jnp.int32)
 
 
-def _sliced_uniform(key: Array, pos: Array, total: int) -> Array:
-    """``jax.random.uniform(key, (total,))[pos]``: randomise the mantissa at
+def _sliced_uniform(key: Array, pos: Array) -> Array:
+    """``jax.random.uniform(key, (n,))[pos]``: randomise the mantissa at
     exponent 1, shift to [0, 1) — bit-for-bit the ``_uniform`` transform."""
-    bits = _sliced_bits(key, pos, total)
+    bits = _sliced_bits(key, pos)
     float_bits = (bits >> np.uint32(9)) | np.float32(1.0).view(np.uint32)
     floats = jax.lax.bitcast_convert_type(float_bits, jnp.float32) - np.float32(1.0)
     return jax.lax.max(
@@ -268,9 +255,9 @@ def _sliced_uniform(key: Array, pos: Array, total: int) -> Array:
     )
 
 
-def _sliced_bernoulli(key: Array, p, pos: Array, total: int) -> Array:
-    """``jax.random.bernoulli(key, p, (total,))[pos]``."""
-    return _sliced_uniform(key, pos, total) < jnp.float32(p)
+def _sliced_bernoulli(key: Array, p, pos: Array) -> Array:
+    """``jax.random.bernoulli(key, p, (n,))[pos]``."""
+    return _sliced_uniform(key, pos) < jnp.float32(p)
 
 
 def generate_key_state(
@@ -296,17 +283,15 @@ def _request_window(
 
     if cfg.skewed:
         n_hot = max(1, int(k * cfg.hot_fraction))
-        pick_hot = _sliced_bernoulli(k_hot, cfg.hot_traffic, pos, r)
-        hot_ids = _sliced_randint(k_key, pos, r, 0, n_hot)
-        cold_ids = _sliced_randint(
-            jax.random.fold_in(k_key, 1), pos, r, n_hot, k
-        )
+        pick_hot = _sliced_bernoulli(k_hot, cfg.hot_traffic, pos)
+        hot_ids = _sliced_randint(k_key, pos, 0, n_hot)
+        cold_ids = _sliced_randint(jax.random.fold_in(k_key, 1), pos, n_hot, k)
         keys = jnp.where(pick_hot, hot_ids, cold_ids).astype(jnp.int32)
     else:
-        keys = _sliced_randint(k_key, pos, r, 0, k).astype(jnp.int32)
+        keys = _sliced_randint(k_key, pos, 0, k).astype(jnp.int32)
 
-    stay = _sliced_bernoulli(k_node, cfg.affinity, pos, r)
-    shift = _sliced_randint(k_other, pos, r, 1, n)
+    stay = _sliced_bernoulli(k_node, cfg.affinity, pos)
+    shift = _sliced_randint(k_other, pos, 1, n)
     nat_of_key = natural[keys]
     nodes = jnp.where(stay, nat_of_key, (nat_of_key + shift) % n).astype(jnp.int32)
 
@@ -314,7 +299,7 @@ def _request_window(
         phase = (pos.astype(jnp.int32) * cfg.diurnal_shifts) // r
         nodes = ((nodes + phase) % n).astype(jnp.int32)
 
-    is_read = _sliced_bernoulli(k_rw, cfg.read_fraction, pos, r)
+    is_read = _sliced_bernoulli(k_rw, cfg.read_fraction, pos)
     return TraceChunk(keys=keys, nodes=nodes, is_read=is_read)
 
 

