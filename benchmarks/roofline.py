@@ -8,7 +8,7 @@ import glob
 import json
 import os
 
-from benchmarks.common import banner, emit, enable_compile_cache
+from benchmarks.common import banner, emit
 
 RESULTS = os.path.join(os.path.dirname(__file__), "results", "dryrun")
 
@@ -69,5 +69,4 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    enable_compile_cache()
     main()

@@ -149,6 +149,10 @@ class PolicyContext(NamedTuple):
                     injection, or ``None`` (the default) for the fault-free
                     program — the sweep then compiles with no membership
                     mask at all (the bit-exact golden path).
+    reseed:         ``[K, N] bool`` durable-store re-seed rows under failure
+                    injection: the initial placement of every key that lost
+                    its last replica to a crash, empty for every other key
+                    (``None`` with faults off).
     """
 
     rtt: Array
@@ -156,6 +160,7 @@ class PolicyContext(NamedTuple):
     capacity_bytes: Array | None
     params: dict
     avail: Array | None = None
+    reseed: Array | None = None
 
 
 # ---------------------------------------------------------------------------
@@ -661,6 +666,14 @@ def _policy_sweep(
     else:
         f = ownership_fraction(counts)  # stage 1: eq. 1, shared
         owners, state = policy.decide(state, store, f, now, ctx)  # stage 2
+
+    # Stage 2b (failure injection): a crash-wiped key the policy assigns no
+    # node — nobody has read it, and silence keeps its emptied placement —
+    # is re-seeded from the durable backing store onto its initial
+    # placement (masked below like any owner: it waits for a live node).
+    if ctx.reseed is not None:
+        dark = ~jnp.any(owners, axis=-1)
+        owners = jnp.where(dark[:, None], ctx.reseed, owners)
 
     # Stage 3 (uniform): dead keys own nothing; expiry purges silence.
     expiry = getattr(policy, "expiry", 0)

@@ -16,7 +16,10 @@ timelines aligned to the engine's chunk axis:
     *start* of chunk ``c`` (True only at a crash event's first chunk).
     ``mode="crash"`` loses data: the node's copies leave the authoritative
     map, keys whose last replica died go dark until the placement daemon
-    re-seeds them from the durable backing store on its next due tick.
+    re-seeds them from the durable backing store on its next due tick
+    with a live target: the nodes their recorded demand selects or, for a
+    key nobody has read, its initial placement. Static policies run no
+    daemon, so their wiped keys stay dark.
     ``mode="partition"`` is loss-free: the map is untouched and the node's
     copies serve again the chunk the partition heals.
 

@@ -262,6 +262,19 @@ def _busy_fold(lat: Array, nodes: Array, n: int) -> Array:
     return jnp.sum(jnp.where(own, lat[:, None], 0.0), axis=0)
 
 
+def _mean_latency(lat_sum: Array, r: int, served: Array | None = None):
+    """Mean latency over the served requests (all ``r`` when ``served`` is
+    ``None``). When nothing was refused the faults-on program takes the
+    very expression the faults-off program uses, ``lat_sum / r``, so an
+    all-up fault schedule agrees with faults off bit for bit whatever the
+    compiler makes of a division by a constant."""
+    if served is None:
+        return lat_sum / r
+    return jnp.where(
+        served == r, lat_sum / r, lat_sum / jnp.maximum(served, 1.0)
+    )
+
+
 def _initial_hosts(
     natural_node: Array, num_keys: int, num_nodes: int, placement: str
 ) -> Array:
@@ -722,7 +735,7 @@ def _simulate(
             r / (jnp.max(busy) / 1000.0),
             hits.astype(jnp.float32)
             / jnp.maximum(reads.astype(jnp.float32), 1.0),
-            lat_sum / r,
+            _mean_latency(lat_sum, r),
             busy,
             zero,  # repl
             zero,  # drop
@@ -1157,7 +1170,9 @@ def _simulate(
                 # down nodes take no new replicas and their held copies are
                 # dropped from the map (rejoin-resync semantics).
                 step_ctx = (
-                    ctx if fault is None else ctx._replace(avail=avail_c)
+                    ctx if fault is None else ctx._replace(
+                        avail=avail_c, reseed=hosts0 & wiped[:, None]
+                    )
                 )
                 stats, pstate, store = policy_masked_step(
                     policy, pstate, store, c, (c % policy.period) == 0,
@@ -1338,18 +1353,15 @@ def _simulate(
         # Served-request mean: unavailable requests produced no latency, so
         # they leave the numerator AND the denominator (throughput keeps
         # dividing the full attempted count — the cluster's offered load).
-        # XLA rewrites the faults-off division by the constant r as a
-        # multiply by its reciprocal; price this one the same way so an
-        # all-up schedule stays bit-exact with faults off.
         served_r = r - fault_totals[0] - fault_totals[1]
-        mean_lat = lat_sum * (1.0 / jnp.maximum(served_r, 1.0))
+        mean_lat = _mean_latency(lat_sum, r, served_r)
         if not routing_totals:
             # SimResult is constructed positionally and the routing
             # counters are a strict prefix of the fault counters — fill
             # their slots with (traced) zeros when only faults are on.
             routing_totals = (zero,) * 4
     else:
-        mean_lat = lat_sum / r
+        mean_lat = _mean_latency(lat_sum, r)
     return (
         r / (makespan_ms / 1000.0),
         hits.astype(jnp.float32)
@@ -1655,9 +1667,10 @@ def _reference_engine(
         rtt=rtt, object_bytes=obj, capacity_bytes=capacity, params=params
     )
 
-    store = _seed_store(
-        _initial_hosts(trace.natural_node, k, n, static.initial_placement), k, n
+    hosts0 = _initial_hosts(
+        trace.natural_node, k, n, static.initial_placement
     )
+    store = _seed_store(hosts0, k, n)
     pstate = static.init(store, ctx)
     contention = _contention_kwargs(cluster, static.read_mode, daemon_interval)
     routing = _routing_kwargs(cluster, k)
@@ -1859,7 +1872,9 @@ def _reference_engine(
             prev_hosts = store.hosts
             if c % static.period == 0:
                 step_ctx = (
-                    ctx if fault is None else ctx._replace(avail=avail_c)
+                    ctx if fault is None else ctx._replace(
+                        avail=avail_c, reseed=hosts0 & wiped[:, None]
+                    )
                 )
                 plan, pstate, store = policy_sweep(
                     static, pstate, store, c, step_ctx
