@@ -1,0 +1,9 @@
+"""Device time under the ``policy_step`` scope (access log + sweeps), in
+milliseconds per 10^6 simulated requests."""
+
+from chipbench.trace_reduce import under
+
+
+def read(ctx):
+    ns = ctx.reduced.time_ns(under("policy_step"))
+    return ns / 1e6 / (ctx.requests / 1e6) if ns > 0 else None
