@@ -45,8 +45,11 @@ def enable_compile_cache() -> str:
     return its directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
     reads it itself and nothing is set here. Otherwise the cache lives at
     the fixed ``<repo>/.jax_cache``: the path is part of the cache key, so
-    a path that moved between runs would never hit. Entry points call this;
-    importing the library never does."""
+    a path that moved between runs would never hit. The key takes in the
+    op metadata too, so a program is never loaded with another tree's scope
+    names, which a profile would show. Entry points call this; importing
+    the library never does."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if path:
         return path

@@ -94,8 +94,6 @@ from repro.core.policy import (
     split_policy,
 )
 from repro.kvsim import (
-    AttributionConfig,
-    FlightRecorderConfig,
     RoutingConfig,
     SimResult,
     TelemetryConfig,
@@ -605,57 +603,6 @@ def run_scale_acceptance(num_requests, num_keys, daemon_interval,
     return row
 
 
-def run_profile(profile_dir, num_requests, num_keys, daemon_interval,
-                policy_spec, replay_backend="jax"):
-    """``--profile``: phase timings + a ``jax.profiler`` trace capture.
-
-    Times the three host-visible phases of one scenario — trace
-    generation, cold compile, warm execute — then re-runs the warm
-    program under ``jax.profiler.trace(profile_dir)`` so the scan-body
-    ``jax.named_scope`` annotations (routing_prepass, contention_prepass,
-    chunk_replay, attribution_*, flight_recorder, policy_step) land in a
-    TensorBoard/Perfetto-loadable capture. Telemetry runs with
-    attribution + flight recorder ON so every annotated phase is present
-    in the program being profiled.
-    """
-    banner(f"profile: phase timings -> {profile_dir}")
-    pol = parse_policy(policy_spec)
-    wl = _wan5_workload(num_requests, num_keys)
-    cluster = wan5_cluster()
-    telem = TelemetryConfig(
-        attribution=AttributionConfig(), flight=FlightRecorderConfig()
-    )
-    t0 = time.perf_counter()
-    jax.block_until_ready(generate_trace(wl, 0).keys)
-    t_trace = time.perf_counter() - t0
-    fn = lambda: run_scenario(
-        wl, cluster, pol, seed=0, daemon_interval=daemon_interval,
-        telemetry=telem, replay_backend=replay_backend,
-    )
-    t0 = time.perf_counter()
-    fn()
-    t_cold = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    fn()
-    t_warm = time.perf_counter() - t0
-    os.makedirs(profile_dir, exist_ok=True)
-    with jax.profiler.trace(profile_dir):
-        fn()
-    phases = {
-        "trace_generation_s": t_trace,
-        "cold_compile_and_run_s": t_cold,
-        "warm_run_s": t_warm,
-        "compile_overhead_s": t_cold - t_warm,
-        "warm_requests_per_s": num_requests / t_warm,
-    }
-    for name, val in phases.items():
-        emit("engine_profile", round(val, 4), name.rsplit("_", 1)[-1],
-             phase=name, policy=policy_spec, backend=replay_backend)
-    print(f"WROTE,{profile_dir} (jax.profiler capture; load in "
-          f"TensorBoard's profile plugin or ui.perfetto.dev)", flush=True)
-    return phases
-
-
 def main(
     num_requests: int = 200_000,
     repeats: int = 5,
@@ -680,7 +627,6 @@ def main(
     scale_requests: int = 10_000_000,
     scale_keys: int = 1_000_000,
     scale_policy: str = "replicated",
-    profile_dir: str | None = None,
 ) -> dict:
     banner("engine_throughput: simulator requests/sec, fused vs pre-fusion")
     if replay_backend is not None:
@@ -814,13 +760,6 @@ def main(
             tuple(trendline_devices), trendline_requests, trendline_keys,
             repeats, daemon_intervals[0], trendline_policy,
         )
-    profile_phases = None
-    if profile_dir:
-        profile_phases = run_profile(
-            profile_dir, num_requests, num_keys_grid[0],
-            daemon_intervals[0], policy_specs[0],
-            replay_backend=backends[0],
-        )
     scale_row = None
     if scale_acceptance:
         # A static policy by design: the criterion is the streamed-trace
@@ -848,8 +787,6 @@ def main(
         metrics["trendline"] = trend_rows
     if scale_row is not None:
         metrics["scale_acceptance"] = scale_row
-    if profile_phases is not None:
-        metrics["profile"] = profile_phases
     write_bench_json(
         "engine_throughput", metrics,
         num_requests=num_requests, repeats=repeats,
@@ -931,12 +868,6 @@ if __name__ == "__main__":
         ">20% vs the baseline (absolute req/s stays warn-only: it is "
         "machine-dependent)",
     )
-    ap.add_argument(
-        "--profile", metavar="DIR", default=None,
-        help="also run one attribution+flight-on scenario under "
-        "jax.profiler.trace(DIR) and report phase timings (the scan "
-        "phases carry jax.named_scope annotations)",
-    )
     args = ap.parse_args()
     main(
         num_requests=args.num_requests,
@@ -962,5 +893,4 @@ if __name__ == "__main__":
         scale_requests=args.scale_requests,
         scale_keys=args.scale_keys,
         scale_policy=args.scale_policy,
-        profile_dir=args.profile,
     )

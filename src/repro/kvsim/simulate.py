@@ -188,6 +188,17 @@ __all__ = [
 
 TRACE_MODES = ("materialized", "streamed")
 
+# The scan body's phases reach the compiled program only as the op_name
+# metadata of its instructions, which is how a profile attributes device
+# time to them. JAX's persistent compilation cache leaves metadata out of
+# its key, so a program compiled from a tree with other scopes (sharing
+# the cache directory) would be loaded in place of this one, with that
+# tree's names: key the cache on the metadata too. This belongs where an
+# entry point turns the cache on, as benchmarks.common.enable_compile_cache
+# does; it stays here until chipbench/run.py's enable_compile_cache sets
+# it, and then goes, with its test in tests/test_named_phases.py.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+
 
 class ShardSpec(NamedTuple):
     """Keyspace sharding of the fused engine, following the
@@ -922,11 +933,14 @@ def _simulate(
             # materialised path reshapes out of the full trace. The final
             # chunk's positions past R are garbage masked by cv.
             c = x
-            pos = c * daemon_interval + jnp.arange(
-                daemon_interval, dtype=jnp.int32
-            )
-            cv = pos < r
-            ck, cn, cr = _request_window(workload, stream_keys, pos, natural)
+            with jax.named_scope("trace_window"):
+                pos = c * daemon_interval + jnp.arange(
+                    daemon_interval, dtype=jnp.int32
+                )
+                cv = pos < r
+                ck, cn, cr = _request_window(
+                    workload, stream_keys, pos, natural
+                )
         else:
             c, ck, cn, cr, cv = x
         if shard.active:
@@ -1084,15 +1098,19 @@ def _simulate(
                     # identical bits across engines and backends.
                     lat = lat + extra
                 lat = jnp.where(served, lat, 0.0)
-            chunk_lat = jnp.sum(lat)
-            chunk_hits = jnp.sum((read_hits & served).astype(jnp.float32))
-            chunk_reads = jnp.sum((cr & served).astype(jnp.float32))
-            chunk_count = jnp.sum(served.astype(jnp.float32))
-            busy = busy + _busy_fold(lat, cn, n)
+            with jax.named_scope("replay_fold"):
+                chunk_lat = jnp.sum(lat)
+                chunk_hits = jnp.sum(
+                    (read_hits & served).astype(jnp.float32)
+                )
+                chunk_reads = jnp.sum((cr & served).astype(jnp.float32))
+                chunk_count = jnp.sum(served.astype(jnp.float32))
+                busy = busy + _busy_fold(lat, cn, n)
             hist = None
-        lat_sum = lat_sum + chunk_lat
-        hits = hits + chunk_hits.astype(jnp.int32)
-        reads = reads + chunk_reads.astype(jnp.int32)
+        with jax.named_scope("replay_fold"):
+            lat_sum = lat_sum + chunk_lat
+            hits = hits + chunk_hits.astype(jnp.int32)
+            reads = reads + chunk_reads.astype(jnp.int32)
         zero = jnp.float32(0.0)
         if fault is not None:
             with jax.named_scope("fault_counters"):
@@ -1127,13 +1145,14 @@ def _simulate(
         # loop constant — numerically identical, O(K·N) cheaper per chunk.
         # Crashes mutate the map even under a static policy, so fault runs
         # always re-sample.
-        if policy.is_active or fault is not None:
-            occ = _node_occupancy(store.hosts, obj_local)
-            if shard.active:
-                occ = jax.lax.psum(occ, shard.axis_name)
-        else:
-            occ = occ0
-        peak = jnp.maximum(peak, occ)
+        with jax.named_scope("occupancy"):
+            if policy.is_active or fault is not None:
+                occ = _node_occupancy(store.hosts, obj_local)
+                if shard.active:
+                    occ = jax.lax.psum(occ, shard.axis_name)
+            else:
+                occ = occ0
+            peak = jnp.maximum(peak, occ)
         if routing is not None:
             # Per-chunk routing diagnostics + decay-LFU cache refresh.
             # Consulted entries re-sync to the PUBLISHED version — a stale
@@ -1161,27 +1180,34 @@ def _simulate(
                 # Down-origin users are offline: their requests leave no
                 # demand signal. Dark reads from LIVE origins DO record —
                 # that demand is how the daemon learns to repair wiped keys.
-                demand_valid = cv if fault is None else cv & avail_c[cn]
-                store = record_accesses(
-                    store, ck, cn, now=c, valid=demand_valid
-                )
-                prev_hosts = store.hosts
-                # The daemon sweeps against the chunk's availability mask:
-                # down nodes take no new replicas and their held copies are
-                # dropped from the map (rejoin-resync semantics).
-                step_ctx = (
-                    ctx if fault is None else ctx._replace(
-                        avail=avail_c, reseed=hosts0 & wiped[:, None]
+                with jax.named_scope("access_log"):
+                    demand_valid = (
+                        cv if fault is None else cv & avail_c[cn]
                     )
-                )
-                stats, pstate, store = policy_masked_step(
-                    policy, pstate, store, c, (c % policy.period) == 0,
-                    step_ctx,
-                )
-            repl = repl + stats.adds.astype(jnp.int32)
-            drop = drop + stats.drops.astype(jnp.int32)
-            evic = evic + stats.expiry_evictions.astype(jnp.int32)
-            cap_evic = cap_evic + stats.capacity_evictions.astype(jnp.int32)
+                    store = record_accesses(
+                        store, ck, cn, now=c, valid=demand_valid
+                    )
+                with jax.named_scope("daemon_sweep"):
+                    prev_hosts = store.hosts
+                    # The daemon sweeps against the chunk's availability
+                    # mask: down nodes take no new replicas and their held
+                    # copies are dropped from the map (rejoin-resync
+                    # semantics).
+                    step_ctx = (
+                        ctx if fault is None else ctx._replace(
+                            avail=avail_c, reseed=hosts0 & wiped[:, None]
+                        )
+                    )
+                    stats, pstate, store = policy_masked_step(
+                        policy, pstate, store, c, (c % policy.period) == 0,
+                        step_ctx,
+                    )
+                    repl = repl + stats.adds.astype(jnp.int32)
+                    drop = drop + stats.drops.astype(jnp.int32)
+                    evic = evic + stats.expiry_evictions.astype(jnp.int32)
+                    cap_evic = cap_evic + stats.capacity_evictions.astype(
+                        jnp.int32
+                    )
             chunk_moves = (
                 stats.adds, stats.drops, stats.expiry_evictions,
                 stats.capacity_evictions,
@@ -1231,10 +1257,11 @@ def _simulate(
                 # the chunk-replay kernel.
                 # Refused (unavailable) requests carry weight 0: latency
                 # histograms cover SERVED requests only.
-                hist = chunk_histogram(
-                    lat, cn * 2 + cr.astype(jnp.int32),
-                    served.astype(jnp.float32), telemetry, n,
-                )
+                with jax.named_scope("telemetry_fold"):
+                    hist = chunk_histogram(
+                        lat, cn * 2 + cr.astype(jnp.int32),
+                        served.astype(jnp.float32), telemetry, n,
+                    )
             ahist = asum = fmeta = fvals = None
             if acfg is not None:
                 # Per-component grouped histograms + per-chunk component
