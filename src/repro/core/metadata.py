@@ -93,24 +93,29 @@ def record_accesses(
     The paper updates metadata per request over HTTP; we fold the whole batch
     with one scatter-add — this is the "non-blocking, off the critical path"
     property taken to its limit (the update *is* part of the fused step).
+    The scatter indexes the ``[K, N]`` counts by ``(key, node)`` in their own
+    shape and leaves their layout to XLA. A flat ``key * N + node`` index
+    needs a row-major view, into and out of which a TPU relays the counts
+    through a layout that pads the N-wide rows to 128 lanes: four passes over
+    the padded array per call, together over three times the scatter's own
+    cost. An integer scatter-add gives the same counts in either form.
     """
     k, n = store.access_counts.shape
     if weights is None:
         weights = jnp.ones_like(keys, dtype=jnp.int32)
-    sel = keys
+    sel, col = keys, nodes
     if valid is not None:
-        weights = jnp.where(valid, weights, 0)
-        sel = jnp.where(valid, keys, k)  # out-of-range rows drop below
-    flat = sel.astype(jnp.int32) * n + nodes.astype(jnp.int32)
-    counts = store.access_counts.reshape(-1)
-    counts = counts.at[flat].add(weights.astype(jnp.int32), mode="drop")
+        # Out-of-range rows drop below; the counts drop a row by its node,
+        # not its key (why: PERF.md §6, the access log's fold into [K, N]).
+        sel = jnp.where(valid, keys, k)
+        col = jnp.where(valid, nodes, n)
+    counts = store.access_counts.at[keys, col].add(
+        weights.astype(jnp.int32), mode="drop"
+    )
     last = store.last_access.at[sel].max(
         jnp.asarray(now, dtype=jnp.int32), mode="drop"
     )
-    return store._replace(
-        access_counts=counts.reshape(k, n),
-        last_access=last,
-    )
+    return store._replace(access_counts=counts, last_access=last)
 
 
 def record_new_keys(

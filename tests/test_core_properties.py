@@ -157,3 +157,79 @@ def test_metadata_record_roundtrip():
     store = record_accesses(store, keys, nodes, now=9)
     assert int(store.access_counts[1, 1]) == 4
     assert int(store.last_access[7]) == 9
+
+
+def _flat_fold(counts, last, keys, nodes, now, weights, valid):
+    """The reference fold: scatter into the counts flattened row-major, by
+    ``key * N + node``, then fold them back to ``[K, N]``."""
+    k, n = counts.shape
+    if weights is None:
+        weights = jnp.ones_like(keys, dtype=jnp.int32)
+    sel = keys
+    if valid is not None:
+        weights = jnp.where(valid, weights, 0)
+        sel = jnp.where(valid, keys, k)
+    flat = sel.astype(jnp.int32) * n + nodes.astype(jnp.int32)
+    counts = counts.reshape(-1).at[flat].add(
+        weights.astype(jnp.int32), mode="drop"
+    )
+    last = last.at[sel].max(jnp.asarray(now, dtype=jnp.int32), mode="drop")
+    return counts.reshape(k, n), last
+
+
+@pytest.mark.parametrize(
+    "k, n, b, weighted, masked, seed",
+    [
+        (1, 1, 8, False, False, 0),
+        (16, 5, 64, False, False, 1),
+        (16, 5, 64, True, True, 2),
+        (97, 3, 500, True, False, 3),
+        (97, 3, 500, False, True, 4),
+        (1000, 5, 4096, True, True, 5),
+    ],
+)
+def test_record_accesses_matches_the_flat_fold(k, n, b, weighted, masked, seed):
+    """The fold in the counts' own ``[K, N]`` shape gives, bit for bit, what
+    the flat-index fold gave: repeated ``(key, node)`` pairs, keys at K - 1,
+    weights, and rows a ``valid`` mask drops."""
+    rng = np.random.default_rng(seed)
+    # Half the rows on the last four keys, so pairs repeat at K - 1.
+    keys = rng.integers(0, k, size=b).astype(np.int32)
+    hot = rng.random(b) < 0.5
+    keys[hot] = rng.integers(max(k - 4, 0), k, size=int(hot.sum()))
+    keys[0] = k - 1
+    nodes = rng.integers(0, n, size=b).astype(np.int32)
+    weights = rng.integers(0, 7, size=b).astype(np.int32) if weighted else None
+    valid = rng.random(b) < 0.7 if masked else None
+    store = create_store(k, n)._replace(
+        access_counts=jnp.asarray(rng.integers(0, 50, size=(k, n)), jnp.int32),
+        last_access=jnp.asarray(rng.integers(0, 20, size=k), jnp.int32),
+    )
+    args = (
+        jnp.asarray(keys), jnp.asarray(nodes), 21,
+        None if weights is None else jnp.asarray(weights),
+        None if valid is None else jnp.asarray(valid),
+    )
+    got = record_accesses(store, *args)
+    want_counts, want_last = _flat_fold(
+        store.access_counts, store.last_access, *args
+    )
+    np.testing.assert_array_equal(np.asarray(got.access_counts), want_counts)
+    np.testing.assert_array_equal(np.asarray(got.last_access), want_last)
+    assert got.access_counts.dtype == jnp.int32
+
+
+def test_record_accesses_folds_the_counts_without_a_reshape():
+    """The lowered fold scatters into the ``[K, N]`` counts in their own
+    shape: no ``reshape`` takes them to or from a flat ``[K * N]`` view,
+    which on a TPU makes XLA relay them through a lane-padded layout."""
+    k, n, b = 1000, 5, 64
+    store = create_store(k, n)
+    text = jax.jit(record_accesses).lower(
+        store, jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
+        jnp.int32(3), jnp.ones((b,), jnp.int32), jnp.ones((b,), bool),
+    ).as_text()
+    assert "stablehlo.scatter" in text
+    reshapes = [line for line in text.splitlines() if "stablehlo.reshape" in line]
+    counts_types = (f"tensor<{k}x{n}xi32>", f"tensor<{k * n}xi32>")
+    assert not [r for r in reshapes if any(t in r for t in counts_types)], reshapes
